@@ -57,6 +57,9 @@ def main() -> None:
                     help="with --snapshot: compile+run once at the "
                          "small scale, write nothing (verify.sh gate)")
     args = ap.parse_args()
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.smoke and not args.snapshot:
         ap.error("--smoke only applies to --snapshot")

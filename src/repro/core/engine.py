@@ -51,7 +51,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, obs
+from repro import obs
 from repro.kernels import ops
 from repro.obs import OocStats
 
@@ -156,7 +156,9 @@ class DistributedEngine:
     mesh: Optional[Mesh]  # None for an OOC-only engine (open_spill)
     axes: Tuple[str, ...] = ("data",)
     method: str = "dstree"
-    stacked: Optional[FrozenIndex] = None  # leading shard axis on arrays
+    # the resident index: every shard padded to one shape and laid end
+    # to end on axis 0, so each device's block IS its shard
+    stacked: Optional[FrozenIndex] = None
     shard_dirs: Optional[Tuple[str, ...]] = None  # spilled store dirs
     # explicit shard count for a MESH-FREE engine (mesh=None +
     # build(keep_resident=False): multi-shard OOC serving without any
@@ -351,12 +353,16 @@ class DistributedEngine:
         spill_dirs = []
         for si in range(s):
             lo, hi = bounds[si], bounds[si + 1]
-            idx = builder(data[lo:hi], hist=hist, key=key, **params)
+            # pulled to the host at once: the builder leaves each
+            # shard on the default device, and holding every shard
+            # there until stacking puts the whole collection on one
+            # chip
+            idx = jax.device_get(
+                builder(data[lo:hi], hist=hist, key=key, **params))
             # re-map ids to global, keep global n_total for r_delta
-            ids = np.asarray(idx.ids)
-            ids = np.where(ids >= 0, ids + lo, -1)
+            ids = np.where(idx.ids >= 0, idx.ids + lo, -1)
             idx = dataclasses.replace(
-                idx, ids=jnp.asarray(ids, jnp.int32), n_total=n)
+                idx, ids=ids.astype(np.int32), n_total=n)
             if spill_dir is not None:
                 d = os.path.join(spill_dir, f"shard_{si:04d}")
                 spill_dirs.append(idx.save(d, codec=codec))
@@ -411,20 +417,26 @@ class DistributedEngine:
 
         spec0 = P(self.axes if len(self.axes) > 1 else self.axes[0])
 
-        def put(x):
+        def put(parts, dtype):
+            # shards end to end on axis 0, straight from the host into
+            # the sharded layout (a jnp.asarray first would stage the
+            # whole collection on one chip). The shard_map body then
+            # gets each shard's 2-D arrays as they are: the eager
+            # dispatch runs every op as its own program, so squeezing a
+            # stacked shard axis there copied the shard's whole data
+            # block, which at 4 GiB per chip no longer fit in HBM
             return jax.device_put(
-                x, NamedSharding(self.mesh, spec0))
+                np.concatenate(parts).astype(dtype, copy=False),
+                NamedSharding(self.mesh, spec0))
 
         base = shards[0]
         self.stacked = FrozenIndex(
-            box_lo=put(jnp.asarray(np.stack(arrs["box_lo"]))),
-            box_hi=put(jnp.asarray(np.stack(arrs["box_hi"]))),
-            offsets=put(jnp.asarray(np.stack(arrs["offsets"]),
-                                    jnp.int32)),
-            data=put(jnp.asarray(np.stack(arrs["data"]))),
-            ids=put(jnp.asarray(np.stack(arrs["ids"]), jnp.int32)),
-            row_norms=put(jnp.asarray(np.stack(arrs["row_norms"]),
-                                      jnp.float32)),
+            box_lo=put(arrs["box_lo"], np.float32),
+            box_hi=put(arrs["box_hi"], np.float32),
+            offsets=put(arrs["offsets"], np.int32),
+            data=put(arrs["data"], arrs["data"][0].dtype),
+            ids=put(arrs["ids"], np.int32),
+            row_norms=put(arrs["row_norms"], np.float32),
             weights=jax.device_put(
                 base.weights, NamedSharding(self.mesh, P())),
             hist=DistanceHistogram(
@@ -737,18 +749,10 @@ class DistributedEngine:
 
         delta, epsilon, nprobe = g.delta, g.epsilon, g.nprobe
 
-        def local(idx_local: FrozenIndex, q) -> SearchResult:
-            # strip the leading shard axis (size 1 per shard)
-            sq = jax.tree_util.tree_map(
-                lambda a: a[0], (idx_local.box_lo, idx_local.box_hi,
-                                 idx_local.offsets, idx_local.data,
-                                 idx_local.ids, idx_local.row_norms))
-            lidx = dataclasses.replace(
-                idx_local, box_lo=sq[0], box_hi=sq[1], offsets=sq[2],
-                data=sq[3], ids=sq[4], row_norms=sq[5])
+        def local(lidx: FrozenIndex, q) -> SearchResult:
             # search_impl, not search: an inner jit under shard_map
             # miscompiles the refinement loop on jax 0.4.x.
-            # repro: allow[jax-while-shard-map] deliberate: this closure is dispatched ONLY through the eager compat.shard_map below (never under jit) precisely because of the 0.4.37 miscompile — ROADMAP pin notes
+            # repro: allow[jax-while-shard-map] deliberate: this closure is dispatched ONLY through the eager jax.shard_map below (never under jit) precisely because of the 0.4.37 miscompile — ROADMAP pin notes
             res = search_impl(
                 lidx, q, k, delta=delta, epsilon=epsilon,
                 nprobe=nprobe, visit_batch=visit_batch,
@@ -777,9 +781,9 @@ class DistributedEngine:
         # 0.4.37; eager execution is correct. Reusing the same wrapped
         # callable via _query_fns still avoids per-call closure
         # rebuilding and retracing.
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check=False,
+            out_specs=out_specs, check_vma=False,
         )
         self._query_fns[cache_key] = fn
         return self._run_resident(fn, idx, queries, k, b)
@@ -804,9 +808,9 @@ class DistributedEngine:
         return QueryResult(*res)
 
     def _dead_stacked_dev(self, mut: _MutView):
-        """The [S, max_rows] stacked tombstone operand for the
-        resident shard_map (device-put with the shard axis on the
-        mesh), rebuilt only when the kill set advances — the
+        """The [S * max_rows] tombstone operand for the resident
+        shard_map (laid out like the stacked index: one block per
+        shard), rebuilt only when the kill set advances — the
         steady-state query between writes reuses the cached device
         array. Same lock-free versioned-cache discipline as
         _dead_cache."""
@@ -816,15 +820,14 @@ class DistributedEngine:
             return hit[1]
         ids_host = self._shard_ids_host
         if ids_host is None:  # e.g. checkpoint-restored stacked index
-            ids_host = [np.asarray(a)
-                        for a in np.asarray(self.stacked.ids)]
+            ids_host = list(np.asarray(self.stacked.ids).reshape(
+                self.n_shards, -1))
             self._shard_ids_host = ids_host
-        masks = np.stack([
+        masks = np.concatenate([
             self._unit_dead(("rshard", si), ids, 0, snap)
             for si, ids in enumerate(ids_host)])
         spec0 = P(self.axes if len(self.axes) > 1 else self.axes[0])
-        dev = jax.device_put(jnp.asarray(masks),
-                             NamedSharding(self.mesh, spec0))
+        dev = jax.device_put(masks, NamedSharding(self.mesh, spec0))
         self._dead_stacked = (snap.kills_version, dev)
         return dev
 
@@ -854,27 +857,20 @@ class DistributedEngine:
                 n_total=idx.n_total, series_len=idx.series_len,
                 row_norms=spec_shard,
             ),
-            spec_shard,  # [S, max_rows] tombstones, one row per shard
+            spec_shard,  # [S * max_rows] tombstones, one block per shard
             P(),         # queries replicated
         )
         delta, epsilon, nprobe = g.delta, g.epsilon, g.nprobe
         joint_n = mut.joint_n
 
-        def local_mut(idx_local: FrozenIndex, dead_l, q) -> SearchResult:
-            sq = jax.tree_util.tree_map(
-                lambda a: a[0], (idx_local.box_lo, idx_local.box_hi,
-                                 idx_local.offsets, idx_local.data,
-                                 idx_local.ids, idx_local.row_norms))
-            lidx = dataclasses.replace(
-                idx_local, box_lo=sq[0], box_hi=sq[1], offsets=sq[2],
-                data=sq[3], ids=sq[4], row_norms=sq[5])
+        def local_mut(lidx: FrozenIndex, dead_l, q) -> SearchResult:
             # search_impl, not search: an inner jit under shard_map
             # miscompiles the refinement loop on jax 0.4.x.
-            # repro: allow[jax-while-shard-map] deliberate: dispatched ONLY through the eager compat.shard_map below (never under jit), same 0.4.37 miscompile rationale as the immutable closure above
+            # repro: allow[jax-while-shard-map] deliberate: dispatched ONLY through the eager jax.shard_map below (never under jit), same 0.4.37 miscompile rationale as the immutable closure above
             res = search_impl(
                 lidx, q, k, delta=delta, epsilon=epsilon,
                 nprobe=nprobe, visit_batch=visit_batch,
-                dead=dead_l[0], n_override=joint_n,
+                dead=dead_l, n_override=joint_n,
                 sync_axes=tuple(axes) if sync_bsf else ())
             all_d = jax.lax.all_gather(res.dists, axes[-1], tiled=False)
             all_i = jax.lax.all_gather(res.ids, axes[-1], tiled=False)
@@ -893,9 +889,9 @@ class DistributedEngine:
             return SearchResult(sd[:, :k], si[:, :k], leaves, rows, lbs)
 
         out_specs = SearchResult(P(), P(), P(), P(), P())
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local_mut, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check=False,
+            out_specs=out_specs, check_vma=False,
         )
         dead_dev = self._dead_stacked_dev(mut)
         qj = jnp.asarray(queries)

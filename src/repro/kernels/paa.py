@@ -2,9 +2,10 @@
 
 The build-time hot loop of iSAX/DSTree indexing: every series in the
 collection is reduced to l segment means. One grid step processes a tile
-of TN series resident in VMEM; the reduction reshapes the lane dimension
-into (l, w) and means over w, which lowers to VPU reductions with the
-sublane-major layout intact.
+of TN series resident in VMEM and contracts it on the MXU against a
+constant [n, l] averaging matrix (1/w inside segment j's columns, 0
+elsewhere). Mosaic cannot reshape the lane dimension into (l, w), so
+the segment reduction is a matmul instead of a reshape + mean.
 """
 
 from __future__ import annotations
@@ -18,10 +19,16 @@ from jax.experimental import pallas as pl
 
 def _paa_kernel(x_ref, out_ref, *, n_segments: int):
     x = x_ref[...].astype(jnp.float32)  # [TN, n]
-    tn, n = x.shape
+    n = x.shape[1]
     w = n // n_segments
-    seg = x.reshape(tn, n_segments, w)
-    out_ref[...] = jnp.mean(seg, axis=-1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n_segments), 0)
+    seg = jax.lax.broadcasted_iota(jnp.int32, (n, n_segments), 1)
+    avg = jnp.where(col // w == seg, jnp.float32(1.0 / w),
+                    jnp.float32(0.0))                 # [n, l]
+    out_ref[...] = jax.lax.dot_general(
+        x, avg, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_segments", "tile",

@@ -48,7 +48,7 @@ def _pq_select_kernel(luts_ref, codes_ref, ids_ref, outd_ref,
 
     luts = luts_ref[...].astype(jnp.float32)  # [TB, m*K]
     codes = codes_ref[...]                    # [TR, m] int32
-    ids = ids_ref[...]                        # [TR, 1] int32
+    idv = ids_ref[...]                        # [1, TR] int32
     tr, m = codes.shape
 
     # one-hot MXU ADC: d[b, i] = sum_j luts[b, j, codes[i, j]]
@@ -57,7 +57,6 @@ def _pq_select_kernel(luts_ref, codes_ref, ids_ref, outd_ref,
     d = jax.lax.dot_general(
         luts, onehot.reshape(tr, m * n_k), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)               # [TB, TR]
-    idv = ids[:, 0][None, :]                              # [1, TR]
     d = jnp.where(idv < 0, jnp.inf, d)
     idm = jnp.broadcast_to(idv, d.shape)
 
@@ -73,7 +72,7 @@ def _pq_select_kernel(luts_ref, codes_ref, ids_ref, outd_ref,
 def pq_adc_select_pallas(
     codes: jax.Array,  # [R, m] int32 pooled code rows
     luts: jax.Array,   # [B, m, K] f32 per-lane ADC tables
-    ids: jax.Array,    # [R, 1] int32 candidate ids, -1 = masked
+    ids: jax.Array,    # [1, R] int32 candidate ids, -1 = masked
     kk: int,
     *,
     tile_b: int = 128,
@@ -90,7 +89,7 @@ def pq_adc_select_pallas(
         in_specs=[
             pl.BlockSpec((tile_b, m * k), lambda i, j: (i, 0)),
             pl.BlockSpec((tile_r, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((tile_r, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, tile_r), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((tile_b, kk), lambda i, j: (i, 0)),

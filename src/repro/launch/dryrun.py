@@ -14,7 +14,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.obs import now  # noqa: E402
 from repro.configs import (ARCH_IDS, SHAPES, get_config,  # noqa: E402
                            shape_applicable)
@@ -181,7 +180,7 @@ def lower_cell(
     })
     # the two required printouts
     print(compiled.memory_analysis())
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     print({k: ca[k] for k in ("flops", "bytes accessed")
            if k in ca})
     return report

@@ -12,7 +12,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.obs import now  # noqa: E402
 from repro.core.histogram import DistanceHistogram  # noqa: E402
 from repro.core.index import FrozenIndex  # noqa: E402
@@ -126,9 +125,9 @@ def lower_search(mesh, *, n_per_shard=2_000_000, series_len=256,
                             jax.lax.psum(res.rows_scanned, axes),
                             jax.lax.psum(res.lb_computed, axes))
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=in_specs,
-                          out_specs=SearchResult(P(), P(), P(), P(), P()),
-                          check=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=SearchResult(P(), P(), P(), P(), P()),
+                       check_vma=False)
     t0 = now()
     lowered = jax.jit(fn).lower(idx, q_sds)
     compiled = lowered.compile()
@@ -168,7 +167,7 @@ def lower_search(mesh, *, n_per_shard=2_000_000, series_len=256,
         "n_total_series": idx.n_total,
     })
     print(compiled.memory_analysis())
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     print({kk: ca[kk] for kk in ("flops", "bytes accessed") if kk in ca})
     return rep
 

@@ -234,9 +234,7 @@ def roofline_report(
     The collective term is parsed from the partitioned HLO with while
     trip-count scaling.
     """
-    from repro import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     raw_flops_dev = float(ca.get("flops", 0.0))
     raw_bytes_dev = float(ca.get("bytes accessed", 0.0))
     flops_dev = (analytic_flops_global / world

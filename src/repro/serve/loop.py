@@ -122,17 +122,23 @@ class ServeFront:
     ``lock_recorder`` (an ``obs.LockOrderRecorder``) wraps the front's
     condition lock so stress tests can assert the full lane+engine
     lock graph stays acyclic.
+
+    ``ooc_opts`` is forwarded to every ``engine.query`` call (the
+    out-of-core knobs, e.g. ``share_gathers``); None passes nothing.
     """
 
     def __init__(self, engine, k: int = 5, *, max_batch: int = 8,
                  admission: Optional[AdmissionController] = None,
                  guarantee_kw: Optional[dict] = None,
+                 ooc_opts: Optional[dict] = None,
                  lock_recorder=None):
         self.engine = engine
         self.k = k
         self.max_batch = max_batch
         self.admission = admission or AdmissionController()
         self.gkw = dict(guarantee_kw or {})
+        self._query_kw = {} if ooc_opts is None \
+            else {"ooc_opts": dict(ooc_opts)}
         lock: Any = threading.RLock()
         if lock_recorder is not None:
             lock = lock_recorder.wrap(lock, "serve.front._cond")
@@ -330,7 +336,8 @@ class ServeFront:
         with obs.span("serve.retrieval_group", kind=g.kind,
                       lanes=lanes, requests=len(group)):
             t0 = obs.now()
-            res = self.engine.query(jnp.asarray(qs), self.k, g)
+            res = self.engine.query(jnp.asarray(qs), self.k, g,
+                                    **self._query_kw)
             ids_np = np.asarray(res.ids)
             dists_np = np.asarray(res.dists)
             group_ms = (obs.now() - t0) * 1e3

@@ -91,6 +91,9 @@ def _default_pq_m(series_len: int) -> int:
     return 1
 
 
+_ENCODE_ROWS = 1 << 18  # rows per pq_encode call in save_index
+
+
 def save_index(
     index: FrozenIndex,
     directory: str,
@@ -167,9 +170,13 @@ def save_index(
                 rows.shape[0], pq_train_rows, replace=False)
             rows = rows[sel]
         cb = pq_train(key, jnp.asarray(rows), m, k=PQ_K, iters=pq_iters)
-        codes = np.asarray(
-            pq_encode(cb, jnp.asarray(data, jnp.float32)), np.uint8)
-        payload = codes
+        # encoded in row chunks: pq_encode materializes an [N, PQ_K]
+        # distance matrix per subspace, which at 4M rows is more HBM
+        # than one v5e chip has
+        payload = np.concatenate([
+            np.asarray(pq_encode(cb, jnp.asarray(
+                data[lo:lo + _ENCODE_ROWS], jnp.float32)), np.uint8)
+            for lo in range(0, data.shape[0], _ENCODE_ROWS)])
         meta["pq_m"] = m
         sidecar["pq_centroids"] = np.asarray(cb.centroids, np.float32)
         sidecar["pq_rotation"] = np.asarray(cb.rotation, np.float32)

@@ -80,6 +80,9 @@ class LeafPrefetcher:
             "store.prefetch.quiesce_timeout", site="reset", **lbl)
         self._c_close_leaked = REGISTRY.counter(
             "store.prefetch.close_leaked", **lbl)
+        # a reader that died on an I/O error leaves the cache on demand
+        # reads only; counted so a caller can tell it happened
+        self._c_died = REGISTRY.counter("store.prefetch.died", **lbl)
         self._c_bytes_read.mark()
         self._c_leaves_read.mark()
         self._thread = threading.Thread(target=self._run, daemon=True)
@@ -253,6 +256,7 @@ class LeafPrefetcher:
                         self._c_leaves_read.inc()
                     self._lock.notify_all()
         except Exception:  # I/O failure: unblock waiters, go demand-only
+            self._c_died.inc()
             with self._lock:
                 self._dead = True
                 self._reading = None

@@ -92,20 +92,19 @@ FIXTURES = {
                         lambda c: c < 3, lambda c: c + 1, state)
                 """),
             "repro/fx/wsm_engine.py": _fix("""
-                from repro import compat
+                import jax
                 from repro.fx.wsm_search import refine
 
                 def local(q):
                     return refine(q)
 
-                fn = compat.shard_map(local, mesh=None, in_specs=(),
-                                      out_specs=())
+                fn = jax.shard_map(local, mesh=None, in_specs=(),
+                                   out_specs=())
                 """),
         },
         "negative": {
             "repro/fx/wsm_neg.py": _fix("""
                 import jax
-                from repro import compat
 
                 def refine(state):
                     # while_loop OUTSIDE any shard_map closure: legal
@@ -115,8 +114,8 @@ FIXTURES = {
                 def local(q):
                     return q * 2
 
-                fn = compat.shard_map(local, mesh=None, in_specs=(),
-                                      out_specs=())
+                fn = jax.shard_map(local, mesh=None, in_specs=(),
+                                   out_specs=())
                 """),
         },
     },

@@ -27,6 +27,17 @@ def test_paa_matches_ref(n_rows, n, l, dtype):
                                dtype == jnp.bfloat16 else 1e-5)
 
 
+@pytest.mark.parametrize("n", [128, 256])
+def test_paa_averaging_matmul_matches_ref(n):
+    """The PAA kernel contracts each tile against an [n, l] averaging
+    matrix (Mosaic cannot reshape the lane dimension into (l, w));
+    at the paper's lengths it must still give ref_paa's means."""
+    x = rand((300, n))
+    got = ops.paa(x, 16, force_pallas=True, tile=64)
+    np.testing.assert_allclose(got, ref.ref_paa(x, 16), rtol=1e-6,
+                               atol=1e-6)
+
+
 @pytest.mark.parametrize("b,L,d", [(1, 3, 16), (5, 100, 32), (128, 512, 16),
                                    (9, 700, 8)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -218,6 +218,23 @@ def test_lane_of_routing():
     assert set(LANES) == {"epsilon", "delta-epsilon", "ng"}
 
 
+def test_front_forwards_ooc_opts():
+    """ooc_opts reaches every engine.query call; without it the call
+    carries no extra keyword (engines need not accept one)."""
+    class _Recorder(_StubEngine):
+        def query(self, qs, k, g, **kw):
+            self.calls.append(kw)
+            return super().query(qs, k, g)
+
+    eng = _Recorder()
+    with ServeFront(eng, k=3, ooc_opts={"share_gathers": True}) as front:
+        front.submit(_req(0)).result(timeout=10.0)
+    assert {"ooc_opts": {"share_gathers": True}} in eng.calls
+    plain = _StubEngine()
+    with ServeFront(plain, k=3) as front:
+        assert "error" not in front.submit(_req(1)).result(timeout=10.0)
+
+
 def test_front_answers_and_releases_admission():
     eng = _StubEngine()
     with ServeFront(eng, k=3, max_batch=4) as front:

@@ -178,6 +178,31 @@ def test_prefetcher_stages_and_takes(built, tmp_path):
         assert pf.take(1) is None              # popped exactly once
 
 
+def test_prefetcher_death_is_counted(built, tmp_path):
+    """A reader thread that dies on an I/O error leaves the cache on
+    demand reads only; the store.prefetch.died counter says so."""
+    from repro import obs
+
+    store = FrozenIndex.load(built.save(str(tmp_path / "idx")),
+                             resident="summaries")
+
+    def broken(leaf):
+        raise OSError("disk gone")
+
+    store.read_leaf = broken
+    pf = LeafPrefetcher(store)
+    died = obs.REGISTRY.counter("store.prefetch.died", prefetch=pf.name)
+    try:
+        pf.schedule([0])
+        deadline = time.time() + 5.0
+        while died.value == 0 and time.time() < deadline:
+            time.sleep(0.01)
+        assert died.value == 1
+        assert pf.take(0) is None
+    finally:
+        pf.close()
+
+
 # ---------------------------------------------------------------- v2 codecs
 
 
