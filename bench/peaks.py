@@ -1,0 +1,21 @@
+"""Published peaks of one chip, keyed by JAX's ``device_kind``.
+
+A device that is not in the table is an error, not a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture):
+    # 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to bench/peaks.py "
+                       "with their source")
+    return PEAKS[device_kind]
