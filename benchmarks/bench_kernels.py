@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.kernels import ops, ref
 
 from .common import csv_line, emit, timeit
@@ -123,38 +122,8 @@ def run(scale: str = "default", out_dir=None) -> List[dict]:
                        f"x{speedup:.1f}_vs_full_sort"))
     rows.append(_pq_fused_memory_row(codes_coop, luts, i_coop1, b,
                                      coop_w))
-    rows.append(_obs_overhead_row(d_solo, i_solo, top_d, top_i))
     emit(rows, out_dir, "bench_kernels")
     return rows
-
-
-def _obs_overhead_row(d_solo, i_solo, top_d, top_i) -> dict:
-    """PR 6 acceptance: tracing DISABLED must cost < 5% on the bench
-    hot path. Times the same jitted merge — the cheapest per-call op
-    of the refinement loop, i.e. the worst case for fixed wrapper
-    overhead — bare vs under a disabled ``obs.span``, whose cost is
-    one module-global flag check + an empty ``with`` block."""
-    assert not obs.enabled(), "benchmarks must run with tracing off"
-    jm = jax.jit(ops.topk_merge)
-
-    def plain():
-        return jm(d_solo, i_solo, top_d, top_i)
-
-    def spanned():
-        with obs.span("bench.noop"):
-            return jm(d_solo, i_solo, top_d, top_i)
-
-    t_plain = timeit(plain, repeats=15, warmup=3)
-    t_span = timeit(spanned, repeats=15, warmup=3)
-    frac = max(0.0, t_span / t_plain - 1.0)
-    row = {"bench": "kernels", "kernel": "obs_span_disabled_overhead",
-           "overhead_frac": round(frac, 4),
-           "us_plain": round(t_plain * 1e6, 2),
-           "us_spanned": round(t_span * 1e6, 2),
-           "threshold_frac": 0.05}
-    print(csv_line("kernel/obs_span_disabled_overhead", t_span * 1e6,
-                   f"overhead_frac={frac:.4f}"))
-    return row
 
 
 def _pq_fused_memory_row(codes_coop, luts, ids, b: int,

@@ -9,8 +9,8 @@ check), on-disk bytes-read, in-memory queries/s, and since PR 4 the
 out-of-core serving rows: engine queries/s over spill-built shards
 and the Scheduler-driven deadline-mixed retrieval front, now with
 per-request serve-latency DISTRIBUTIONS (p50/p95/p99 via the
-repro.obs log-bucketed histograms) and the tracing-disabled overhead
-row, and since PR 10 the streaming-ingest freshness row (insert ->
+repro.obs log-bucketed histograms), and the streaming-ingest
+freshness row (insert ->
 first-retrievable lag through the ServeFront write lane,
 docs/INGEST.md) — so later PRs can diff the perf trajectory without
 rerunning whole suites.
@@ -72,12 +72,6 @@ def collect(scale: str = "default", smoke: bool = False) -> dict:
         ({k: v for k, v in r.items()
           if k not in ("bench", "kernel")}
          for r in krows if r.get("kernel") == "pq_adc_select_memory"),
-        None)
-    obs_overhead = next(
-        ({k: v for k, v in r.items()
-          if k not in ("bench", "kernel")}
-         for r in krows
-         if r.get("kernel") == "obs_span_disabled_overhead"),
         None)
 
     # --- in-memory queries/s (the paper's best tree, eps=1) ---
@@ -185,7 +179,6 @@ def collect(scale: str = "default", smoke: bool = False) -> dict:
         "serve": serve,
         "serve_load": serve_load,
         "freshness": freshness,
-        "obs_overhead": obs_overhead,
     }
 
 

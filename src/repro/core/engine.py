@@ -75,7 +75,10 @@ class QueryResult(NamedTuple):
     (docs/ANALYSIS.md). ``stats`` is None on the resident shard_map
     path (no I/O to account) and an aggregated OocStats on the
     out-of-core path (per-shard schemas under ``.stats.shards``,
-    degradation triple when shards were lost — docs/FAULT.md)."""
+    degradation triple when shards were lost — docs/FAULT.md).
+    ``dispatch_s`` is the resident path's host seconds inside the
+    eager shard_map call (the ``engine.dispatch`` span); 0.0 on the
+    out-of-core path."""
 
     dists: jax.Array           # [B, k] Euclidean distances, ascending
     ids: jax.Array             # [B, k] global row ids (-1 = missing)
@@ -83,6 +86,7 @@ class QueryResult(NamedTuple):
     rows_scanned: jax.Array    # [B] int32, summed over shards
     lb_computed: jax.Array     # scalar int32
     stats: Optional[OocStats] = None
+    dispatch_s: float = 0.0
 
 class EngineSegment(NamedTuple):
     """One compacted delta segment (docs/INGEST.md): the leaf-
@@ -790,22 +794,24 @@ class DistributedEngine:
 
     def _run_resident(self, fn, idx, queries, k: int, b: int
                       ) -> QueryResult:
-        """Dispatch the (cached) shard_map'ed resident query, wrapped
-        in a span when tracing is enabled. The block_until_ready is
-        span-only: the untraced path keeps its async dispatch. The
-        resident path has no I/O to account, so ``stats`` is None —
-        thread-safe by construction (eager shard_map dispatch touches
-        no per-query engine state)."""
-        if not obs.enabled():
-            return QueryResult(*fn(idx, queries))
+        """Dispatch the (cached) shard_map'ed resident query under an
+        ``engine.query`` span, timing the eager call as
+        ``engine.dispatch`` (``QueryResult.dispatch_s``). Nothing here
+        syncs the device; the span's attributes are read back only
+        while obs records. The resident path has no I/O to account, so
+        ``stats`` is None — thread-safe by construction (eager
+        shard_map dispatch touches no per-query engine state)."""
+        dispatch = obs.Tally("engine.dispatch")
         with obs.span("engine.query", path="resident", lanes=b, k=k,
                       shards=self.n_shards) as sp:
-            res = fn(idx, queries)
-            jax.block_until_ready(res.dists)
-            sp.set(leaves_visited=int(np.asarray(
-                       res.leaves_visited).sum()),
-                   rows_scanned=int(np.asarray(res.rows_scanned).sum()))
-        return QueryResult(*res)
+            with dispatch:
+                res = fn(idx, queries)
+            if obs.enabled():
+                sp.set(leaves_visited=int(np.asarray(
+                           res.leaves_visited).sum()),
+                       rows_scanned=int(np.asarray(
+                           res.rows_scanned).sum()))
+        return QueryResult(*res, dispatch_s=dispatch.seconds)
 
     def _dead_stacked_dev(self, mut: _MutView):
         """The [S * max_rows] tombstone operand for the resident
@@ -895,21 +901,21 @@ class DistributedEngine:
         )
         dead_dev = self._dead_stacked_dev(mut)
         qj = jnp.asarray(queries)
-        if not obs.enabled():
-            base = QueryResult(*fn(idx, dead_dev, qj))
-            return self._fold_mutable(base, mut, qj, k, g,
-                                      visit_batch, resident=True)
+        dispatch = obs.Tally("engine.dispatch")
         with obs.span("engine.query", path="resident+delta", lanes=b,
                       k=k, shards=self.n_shards,
                       delta_rows=mut.snap.live_rows,
                       segments=len(mut.snap.segments)) as sp:
-            res = fn(idx, dead_dev, qj)
-            jax.block_until_ready(res.dists)
-            out = self._fold_mutable(QueryResult(*res), mut, qj, k, g,
-                                     visit_batch, resident=True)
-            sp.set(leaves_visited=int(np.asarray(
-                       out.leaves_visited).sum()),
-                   rows_scanned=int(np.asarray(out.rows_scanned).sum()))
+            with dispatch:
+                res = fn(idx, dead_dev, qj)
+            out = self._fold_mutable(
+                QueryResult(*res, dispatch_s=dispatch.seconds), mut, qj,
+                k, g, visit_batch, resident=True)
+            if obs.enabled():
+                sp.set(leaves_visited=int(np.asarray(
+                           out.leaves_visited).sum()),
+                       rows_scanned=int(np.asarray(
+                           out.rows_scanned).sum()))
         return out
 
     def _fold_mutable(self, base: QueryResult, mut: _MutView, qj,
@@ -975,6 +981,7 @@ class DistributedEngine:
             rows_scanned=jnp.asarray(rows, jnp.int32),
             lb_computed=jnp.int32(lbs),
             stats=base.stats,
+            dispatch_s=base.dispatch_s,
         )
 
     # ------------------------------------------------------------------

@@ -236,23 +236,20 @@ def search(index: FrozenIndex, queries: jax.Array, k: int,
     loose ``delta=``/``epsilon=``/``nprobe=`` kwargs still work for one
     release via a shim that emits APIDeprecationWarning (an error under
     scripts/verify.sh, and the ``guarantee-kwargs`` analysis rule fails
-    in-repo callers). When span tracing is enabled (repro.obs) the call
-    is wrapped in a ``core.search`` span — blocking on the result so
-    the span measures the device work; untraced calls keep jit's async
-    dispatch and pay only this one flag check."""
+    in-repo callers). The call is a ``core.search`` span (repro.obs),
+    which never syncs the device: jit's async dispatch is kept, and
+    the span's visit counts are read back only while obs records."""
     from repro import obs
     from .spec import coerce_guarantee
 
     g = coerce_guarantee(g, kw, caller="search")
     kw.update(delta=g.delta, epsilon=g.epsilon, nprobe=g.nprobe)
-    if not obs.enabled():
-        return _search_jit(index, queries, k, **kw)
     with obs.span("core.search", lanes=queries.shape[0], k=k,
                   leaves=index.num_leaves) as sp:
         res = _search_jit(index, queries, k, **kw)
-        jax.block_until_ready(res.dists)
-        sp.set(leaves_visited=int(jnp.sum(res.leaves_visited)),
-               rows_scanned=int(jnp.sum(res.rows_scanned)))
+        if obs.enabled():
+            sp.set(leaves_visited=int(jnp.sum(res.leaves_visited)),
+                   rows_scanned=int(jnp.sum(res.rows_scanned)))
     return res
 
 

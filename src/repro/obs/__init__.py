@@ -2,10 +2,12 @@
 
 Two halves (docs/OBSERVABILITY.md):
 
-  trace    opt-in span tracer (disabled by default, near-zero cost
-           off): nestable context-manager spans on ONE monotonic
-           clock (``obs.now``), per-query :class:`QueryProfile`
-           summaries, Chrome trace-event JSON export.
+  trace    one span tracer: spans mirror into the JAX profiler's
+           trace while it collects; opt-in recording (disabled by
+           default, near-zero cost off) of nestable spans on ONE
+           monotonic clock (``obs.now``), per-query
+           :class:`QueryProfile` summaries, Chrome trace-event JSON
+           export; :class:`Tally`, the always-on counter of a span.
   metrics  always-on process-wide registry of labeled counters /
            gauges / log-bucketed histograms (p50/p95/p99).
 
@@ -21,16 +23,16 @@ from .lockorder import (LockOrderError, LockOrderRecorder, wrap
 from .metrics import (GROWTH, REGISTRY, Counter, Gauge, Histogram,
                       MetricsRegistry, registry)
 from .stats import OocStats
-from .trace import (NULL_SPAN, QueryProfile, Span, Tracer,
+from .trace import (NULL_SPAN, QueryProfile, Span, Tally, Tracer,
                     chrome_events, clear, disable, dump_chrome_trace,
-                    enable, enabled, last_profile, now, profile, span,
-                    tracer)
+                    enable, enabled, last_profile, now, profile,
+                    profiling, span, tracer)
 
 __all__ = [
     "GROWTH", "REGISTRY", "Counter", "Gauge", "Histogram",
     "LockOrderError", "LockOrderRecorder", "wrap_lock",
     "MetricsRegistry", "registry", "OocStats", "NULL_SPAN",
-    "QueryProfile", "Span", "Tracer", "chrome_events", "clear",
-    "disable", "dump_chrome_trace", "enable", "enabled",
-    "last_profile", "now", "profile", "span", "tracer",
+    "QueryProfile", "Span", "Tally", "Tracer", "chrome_events",
+    "clear", "disable", "dump_chrome_trace", "enable", "enabled",
+    "last_profile", "now", "profile", "profiling", "span", "tracer",
 ]

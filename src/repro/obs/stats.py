@@ -22,6 +22,11 @@ Field groups:
                    slack at stop (mean over lanes attributed to that
                    condition; slack = how far past the threshold the
                    stop fired, in squared-distance units).
+  host time        where the loop's host seconds go (always on, taken
+                   from the same stamps as the ``ooc.gather`` /
+                   ``ooc.sync`` spans): the loop from its first to its
+                   last iteration, the gathers, and the device->host
+                   reads with their count.
   engine fold      ``shards`` holds the per-shard OocStats when the
                    DistributedEngine aggregates a cross-shard query.
 
@@ -70,6 +75,11 @@ class OocStats:
     stop_exhausted: int = 0      # lanes that ran out of rank budget
     delta_slack: float = 0.0     # mean (1+eps)^2*rd^2 - bsf at delta stops
     eps_slack: float = 0.0       # mean next_lb*(1+eps)^2 - bsf at eps stops
+    # ---- host time of the loop (obs.Tally over its spans)
+    loop_s: float = 0.0          # first to last iteration, host seconds
+    gather_s: float = 0.0        # summed ooc.gather spans
+    sync_s: float = 0.0          # summed ooc.sync spans
+    host_syncs: int = 0          # device->host reads in the loop
     # ---- fault tolerance (engine fold; per-shard entries carry their
     # own retries/failovers, the degradation triple is engine-level —
     # docs/FAULT.md)
@@ -117,6 +127,7 @@ class OocStats:
         "bytes_read_rerank", "dataset_bytes", "iterations",
         "frontier_refills", "leaves_visited", "rows_scanned",
         "stop_delta", "stop_epsilon", "stop_exhausted",
+        "loop_s", "gather_s", "sync_s", "host_syncs",
         "retries", "failovers",
     )
 

@@ -6,14 +6,24 @@ span; the SAME clock is exported to the serving front
 queue-wait arithmetic across modules is coherent by construction —
 never mix this with ``time.monotonic()`` or wall-clock time.
 
-Tracing is DISABLED by default and near-zero cost when disabled:
-:func:`span` returns a shared no-op context manager without touching
-the tracer, so instrumented hot paths pay one module-global bool check
-plus an empty ``with`` block (~sub-µs; measured as the
-``obs_span_disabled_overhead`` row in benchmarks/bench_kernels.py,
-< 5% of the cheapest merge kernel's call time).
+:func:`span` is the one tracing entry point, in three states:
 
-When enabled, spans nest through a thread-local stack (each thread
+  profiler on   while the JAX profiler collects (``jax.profiler.trace``
+                / ``start_trace``), every span also opens a
+                ``jax.profiler.TraceAnnotation`` under its name, so the
+                program's spans land in the ``.xplane.pb`` beside the
+                device's ops, on the device trace's clock. This holds
+                whether or not :func:`enable` was called.
+  obs on        (:func:`enable`) a :class:`Span` records into the
+                tracer's list on ``now`` (and mirrors, as above).
+  both off      the shared no-op :data:`NULL_SPAN`: one module-global
+                flag check and one profiler flag read, no allocation.
+
+Spans never sync the device: a span times the host's side of what it
+encloses (dispatch, host reads, I/O). Device time comes from the device
+trace.
+
+When obs is on, spans nest through a thread-local stack (each thread
 builds its own subtree; ids are process-unique), finished spans land
 in the tracer's ordered list, and two consumers read them:
 
@@ -27,6 +37,11 @@ in the tracer's ordered list, and two consumers read them:
                       (chrome://tracing, Perfetto) — ``ph="X"``
                       complete events, µs timestamps, span attrs in
                       ``args``.
+
+:class:`Tally` is the always-on counter of a span: the count and host
+seconds of the regions it times, each also a span of its name, so the
+counter a result carries and the span a trace shows come from the same
+two stamps.
 
 Span taxonomy and attribute names are documented in
 docs/OBSERVABILITY.md.
@@ -42,17 +57,22 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 #: THE monotonic clock of the whole serving stack (satellite: was
 #: time.monotonic in serve/batching vs time.perf_counter in
 #: launch/serve — queue-wait subtraction across the two was
 #: incoherent).
 now = time.perf_counter
 
+#: True while the JAX profiler collects (a C++ flag read).
+profiling = TraceAnnotation.is_enabled
+
 _enabled = False
 
 
 def enabled() -> bool:
-    """Fast global flag — the only cost tracing adds when off."""
+    """True while obs records :class:`Span` objects (:func:`enable`)."""
     return _enabled
 
 
@@ -89,6 +109,18 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _Annotation(TraceAnnotation):
+    """What :func:`span` hands out while the profiler collects and obs
+    is off: the profiler's annotation under the span's name, with the
+    span surface (``set``/``add`` record nothing)."""
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def add(self, key: str, n) -> None:
+        pass
+
+
 @dataclasses.dataclass
 class Span:
     """One timed region. Context-manager: ``with tracer.span(...) as
@@ -103,6 +135,8 @@ class Span:
     tid: int = 0
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
     _tracer: Optional["Tracer"] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    _ann: Optional[TraceAnnotation] = dataclasses.field(
         default=None, repr=False, compare=False)
 
     def set(self, **attrs) -> None:
@@ -120,11 +154,17 @@ class Span:
         return (self.t1 - self.t0) * 1e3
 
     def __enter__(self) -> "Span":
+        if profiling():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._tracer._push(self)
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self._tracer._pop(self)
         return False
 
@@ -217,12 +257,50 @@ def tracer() -> Tracer:
 
 
 def span(name: str, **attrs):
-    """The instrumentation entry point: a real span when tracing is
-    enabled, the shared no-op otherwise. ``with obs.span("x") as sp:``
-    works identically in both states."""
-    if not _enabled:
-        return NULL_SPAN
-    return _TRACER.span(name, **attrs)
+    """The instrumentation entry point: a recording :class:`Span` when
+    obs is enabled, the profiler's annotation while only the profiler
+    collects, the shared no-op otherwise. ``with obs.span("x") as sp:``
+    works identically in every state."""
+    if _enabled:
+        return _TRACER.span(name, **attrs)
+    if profiling():
+        return _Annotation(name)
+    return NULL_SPAN
+
+
+class Tally:
+    """Count and host seconds of the regions it times, each region also
+    an :func:`span` of ``name``. With obs recording, the seconds are the
+    spans' own ``t0``/``t1``, so the counter and the spans cannot
+    drift. Always on; one instance times one region at a time (reuse
+    it, do not nest it in itself)."""
+
+    __slots__ = ("name", "count", "seconds", "_sp", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.count = 0
+        self.seconds = 0.0
+        self._sp = NULL_SPAN
+        self._t0 = 0.0
+
+    def __enter__(self):
+        sp = self._sp = span(self.name)
+        sp.__enter__()
+        self._t0 = sp.t0 if type(sp) is Span else now()
+        return sp
+
+    def __exit__(self, *exc) -> bool:
+        sp, self._sp = self._sp, NULL_SPAN
+        if type(sp) is Span:
+            sp.__exit__(*exc)
+            t1 = sp.t1
+        else:
+            t1 = now()
+            sp.__exit__(*exc)
+        self.count += 1
+        self.seconds += t1 - self._t0
+        return False
 
 
 def clear() -> None:

@@ -216,8 +216,9 @@ class Scheduler:
                 res = engine.query(jnp.asarray(qs), k, g)
                 # host copies block on the device result, so the group
                 # time covers the full engine call
-                ids_np = np.asarray(res.ids)
-                dists_np = np.asarray(res.dists)
+                with obs.span("serve.fetch"):
+                    ids_np = np.asarray(res.ids)
+                    dists_np = np.asarray(res.dists)
                 group_ms = (obs.now() - t0) * 1e3
             obs.REGISTRY.histogram(
                 "serve.retrieval_ms", kind=g.kind).record(group_ms)

@@ -245,7 +245,8 @@ class ServeFront:
 
     def _worker(self, lane: str) -> None:
         while True:
-            batch = self._take(lane)
+            with obs.span("serve.lane.wait", lane=lane):
+                batch = self._take(lane)
             if batch is None:
                 return
             obs.REGISTRY.histogram(
@@ -338,8 +339,9 @@ class ServeFront:
             t0 = obs.now()
             res = self.engine.query(jnp.asarray(qs), self.k, g,
                                     **self._query_kw)
-            ids_np = np.asarray(res.ids)
-            dists_np = np.asarray(res.dists)
+            with obs.span("serve.fetch"):
+                ids_np = np.asarray(res.ids)
+                dists_np = np.asarray(res.dists)
             group_ms = (obs.now() - t0) * 1e3
         obs.REGISTRY.histogram(
             "serve.retrieval_ms", kind=g.kind).record(group_ms)

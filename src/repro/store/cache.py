@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.obs import REGISTRY
 
 from .layout import LeafStore
@@ -222,32 +223,36 @@ class DeviceLeafCache:
     def _fill(self, leaves: List[int], slot_ids: List[int]) -> None:
         m, c = self.store.max_leaf, self.store.payload_cols
         buf = np.zeros((len(leaves), m, c), self.store.data_dtype)
-        for j, lf in enumerate(leaves):
-            staged = None
-            if self.prefetcher is not None:
-                staged = self.prefetcher.take(lf)
-            if staged is not None:
-                buf[j] = staged
-                self._c_prefetch_hits.inc()  # bytes already counted by
-                #                              the prefetcher thread
-            else:
-                self.store.read_leaf(lf, out=buf[j])
-                self._c_bytes_read_sync.inc(self.store.leaf_nbytes(lf))
+        with obs.span("store.read"):
+            for j, lf in enumerate(leaves):
+                staged = None
+                if self.prefetcher is not None:
+                    staged = self.prefetcher.take(lf)
+                if staged is not None:
+                    buf[j] = staged
+                    self._c_prefetch_hits.inc()  # bytes already counted
+                    #                              by the prefetcher thread
+                else:
+                    self.store.read_leaf(lf, out=buf[j])
+                    self._c_bytes_read_sync.inc(
+                        self.store.leaf_nbytes(lf))
         self._c_bytes_h2d.inc(buf.nbytes)  # real misses, not the pad
-        # pad the batch to the next power of two by REPEATING the last
-        # row (idempotent duplicate scatter) so the jitted scatter sees
-        # O(log capacity) distinct shapes instead of one per miss count
-        pad = 1 << (len(leaves) - 1).bit_length()
-        ids_arr = np.empty(pad, np.int32)
-        ids_arr[: len(leaves)] = slot_ids
-        ids_arr[len(leaves):] = slot_ids[-1]
-        if pad != len(leaves):
-            buf = np.concatenate(
-                [buf, np.broadcast_to(buf[-1], (pad - len(leaves),) +
-                                      buf.shape[1:])])
-        with self._lock:
-            self.slots = _scatter_fill(
-                self.slots, jnp.asarray(ids_arr), jnp.asarray(buf))
+        with obs.span("store.h2d"):
+            # pad the batch to the next power of two by REPEATING the
+            # last row (idempotent duplicate scatter) so the jitted
+            # scatter sees O(log capacity) distinct shapes instead of
+            # one per miss count
+            pad = 1 << (len(leaves) - 1).bit_length()
+            ids_arr = np.empty(pad, np.int32)
+            ids_arr[: len(leaves)] = slot_ids
+            ids_arr[len(leaves):] = slot_ids[-1]
+            if pad != len(leaves):
+                buf = np.concatenate(
+                    [buf, np.broadcast_to(buf[-1], (pad - len(leaves),)
+                                          + buf.shape[1:])])
+            with self._lock:
+                self.slots = _scatter_fill(
+                    self.slots, jnp.asarray(ids_arr), jnp.asarray(buf))
 
     # ------------------------------------------------------------------
     @property

@@ -247,8 +247,11 @@ def _host_refine(
 
     Telemetry is read-only observation of values the loop already
     syncs to host (active mask, ranks, bsf, next_lb) — it cannot
-    change visit order, scoring, or stopping arithmetic. Spans are
-    emitted only when tracing is enabled (obs.enabled()).
+    change visit order, scoring, or stopping arithmetic. Every
+    device->host read goes through one helper that counts and times it
+    under an ``ooc.sync`` span; the loop's host time (``loop_s``,
+    ``gather_s``, ``sync_s``, ``host_syncs``) is always on. Spans never
+    sync the device, so a traced loop keeps the untraced schedule.
 
     ``fault`` is the serving-layer injection hook (duck-typed —
     serve/fault.FaultContext in production): ``fault.check("gather")``
@@ -268,14 +271,20 @@ def _host_refine(
     v = int(visit_batch)
     depth = max(1, int(prefetch_depth))
     traced = obs.enabled()
+    gather_t = obs.Tally("ooc.gather")
+    sync_t = obs.Tally("ooc.sync")
+
+    def host(x) -> np.ndarray:
+        """Every device->host read of the loop: counted, timed and
+        spanned as ``ooc.sync``."""
+        with sync_t:
+            return np.asarray(x)
 
     ctx = src.query_ctx(queries)
     if dead is not None:
         ctx = ctx._replace(dead=jnp.asarray(dead))
     with obs.span("ooc.filter", leaves=L, lanes=b):
         lb_sq = _filter_stage(res, queries)  # [B, L], stays on device
-        if traced:  # make the span cover the device work it launched
-            jax.block_until_ready(lb_sq)
 
     # frontier width F covers this iteration's visits, the next_lb
     # probe AND the prefetch lookahead (depth extra windows); ANY
@@ -312,32 +321,28 @@ def _host_refine(
     slack_sum = {"delta": 0.0, "epsilon": 0.0}
     slack_n = {"delta": 0, "epsilon": 0}
 
+    t_loop = obs.now()
     while active.any():
-        it_span = obs.span("ooc.iteration", iter=iters)
-        it_span.__enter__()
-        # the try/finally matters under fault injection: an exception
-        # escaping mid-iteration (injected fault, attempt deadline)
-        # must still pop this span off the thread's stack, or every
-        # later span in this worker thread would nest under a corpse
-        try:
-            active_j = jnp.asarray(active)
-            # mirror frontier_tick's refill predicate (same F/
-            # lookahead/pos inputs) to count lane-refill events; pos
-            # is host-read BEFORE the tick so the count observes,
-            # never participates
-            pos_host = np.asarray(fr.pos)
-            refills += int(
-                (active & (pos_host > F - 1 - lookahead)).sum())
-            fr, leaf_j = _frontier_tick(fr, lb_sq, active_j,
-                                        v=v, lookahead=lookahead)
-            leaf = np.asarray(leaf_j)
+        with obs.span("ooc.iteration", iter=iters):
+            with obs.span("ooc.tick"):
+                active_j = jnp.asarray(active)
+                # mirror frontier_tick's refill predicate (same F/
+                # lookahead/pos inputs) to count lane-refill events;
+                # pos is host-read BEFORE the tick so the count
+                # observes, never participates
+                pos_host = host(fr.pos)
+                refills += int(
+                    (active & (pos_host > F - 1 - lookahead)).sum())
+                fr, leaf_j = _frontier_tick(fr, lb_sq, active_j,
+                                            v=v, lookahead=lookahead)
+                leaf = host(leaf_j)
 
             rk = rank[:, None] + np.arange(v)[None, :]
             in_range = rk < max_rank
             ok = in_range & active[:, None]
             if fault is not None:
                 fault.check("gather")
-            with obs.span("ooc.gather") as g_span:
+            with gather_t as g_span:
                 # demand-path (sync) reads only: the prefetcher thread
                 # lands its bytes concurrently, so a cache.bytes_read
                 # delta here would be racy — the root span carries the
@@ -351,16 +356,17 @@ def _host_refine(
             # overlap: stage the next `depth` visit windows while the
             # device scores this one (nearest window first — it is
             # read first)
-            windows = []
-            for d in range(1, depth + 1):
-                base = np.minimum(rank + d * v, max_rank)
-                ok_d = ((base[:, None] + np.arange(v)[None, :])
-                        < max_rank) & active[:, None]
-                if ok_d.any():
-                    windows.append(
-                        (np.asarray(_frontier_window(fr, d * v, v)),
-                         ok_d))
-            src.prefetch(windows)
+            with obs.span("ooc.prefetch"):
+                windows = []
+                for d in range(1, depth + 1):
+                    base = np.minimum(rank + d * v, max_rank)
+                    ok_d = ((base[:, None] + np.arange(v)[None, :])
+                            < max_rank) & active[:, None]
+                    if ok_d.any():
+                        windows.append(
+                            (host(_frontier_window(fr, d * v, v)),
+                             ok_d))
+                src.prefetch(windows)
 
             if fault is not None:
                 fault.check("score")
@@ -373,59 +379,55 @@ def _host_refine(
                 else:
                     top_d, top_i = src.score(ctx, g, g.valid, top_d,
                                              top_i, share=False)
-                if traced:
-                    jax.block_until_ready(top_d)
 
-            valid_np = np.asarray(g.valid)
-            leaves_visited += np.where(active, in_range.sum(1), 0)
-            rows_scanned += np.where(active, valid_np.sum(1), 0)
+            with obs.span("ooc.stop"):
+                valid_np = host(g.valid)
+                leaves_visited += np.where(active, in_range.sum(1), 0)
+                rows_scanned += np.where(active, valid_np.sum(1), 0)
 
-            fr, next_lb_j = _frontier_advance(fr, active_j, v=v)
-            rank_next = np.minimum(rank + v, max_rank)
-            exhausted = rank_next >= max_rank
-            next_lb = np.asarray(next_lb_j).astype(np.float32)
-            bsf = np.asarray(top_d[:, k - 1])      # f32, sync point
-            stop = refine.stop_mask(next_lb, exhausted, bsf,
-                                    eps_mult, rd_sq)
-            # attribute each newly stopped lane to ONE condition
-            # (priority delta > epsilon > exhausted — a lane can
-            # satisfy several at once) and measure the slack at stop:
-            # how far past the threshold the predicate fired, in
-            # squared-distance units
-            newly = active & stop
-            if newly.any():
-                m_delta = newly & (bsf <= eps_mult * rd_sq)
-                m_eps = newly & ~m_delta & (next_lb * eps_mult > bsf)
-                m_exh = newly & ~m_delta & ~m_eps
-                stop_n["delta"] += int(m_delta.sum())
-                stop_n["epsilon"] += int(m_eps.sum())
-                stop_n["exhausted"] += int(m_exh.sum())
-                if m_delta.any():
-                    s = (eps_mult * rd_sq - bsf)[m_delta]
-                    slack_sum["delta"] += float(s.sum())
-                    slack_n["delta"] += int(m_delta.sum())
-                # epsilon slack only over finite next_lb: an inf
-                # next_lb means the frontier pool ran dry, not a
-                # measurable margin
-                m_eps_f = m_eps & np.isfinite(next_lb)
-                if m_eps_f.any():
-                    s = (next_lb * eps_mult - bsf)[m_eps_f]
-                    slack_sum["epsilon"] += float(s.sum())
-                    slack_n["epsilon"] += int(m_eps_f.sum())
+                fr, next_lb_j = _frontier_advance(fr, active_j, v=v)
+                rank_next = np.minimum(rank + v, max_rank)
+                exhausted = rank_next >= max_rank
+                next_lb = host(next_lb_j).astype(np.float32)
+                bsf = host(top_d[:, k - 1])       # f32
+                stop = refine.stop_mask(next_lb, exhausted, bsf,
+                                        eps_mult, rd_sq)
+                # attribute each newly stopped lane to ONE condition
+                # (priority delta > epsilon > exhausted — a lane can
+                # satisfy several at once) and measure the slack at
+                # stop: how far past the threshold the predicate
+                # fired, in squared-distance units
+                newly = active & stop
+                if newly.any():
+                    m_delta = newly & (bsf <= eps_mult * rd_sq)
+                    m_eps = newly & ~m_delta & (next_lb * eps_mult > bsf)
+                    m_exh = newly & ~m_delta & ~m_eps
+                    stop_n["delta"] += int(m_delta.sum())
+                    stop_n["epsilon"] += int(m_eps.sum())
+                    stop_n["exhausted"] += int(m_exh.sum())
+                    if m_delta.any():
+                        s = (eps_mult * rd_sq - bsf)[m_delta]
+                        slack_sum["delta"] += float(s.sum())
+                        slack_n["delta"] += int(m_delta.sum())
+                    # epsilon slack only over finite next_lb: an inf
+                    # next_lb means the frontier pool ran dry, not a
+                    # measurable margin
+                    m_eps_f = m_eps & np.isfinite(next_lb)
+                    if m_eps_f.any():
+                        s = (next_lb * eps_mult - bsf)[m_eps_f]
+                        slack_sum["epsilon"] += float(s.sum())
+                        slack_n["epsilon"] += int(m_eps_f.sum())
             active = active & ~stop
             rank = rank_next
             iters += 1
-        finally:
-            it_span.__exit__(None, None, None)
+    loop_s = obs.now() - t_loop
 
     with obs.span("ooc.finalize") as f_span:
         top_d, top_i, rerank_bytes = src.finalize(ctx, top_d, top_i, k)
-        if traced:
-            jax.block_until_ready(top_d)
-            # rerank-specific attr name: the ooc.query root owns the
-            # subtree's single "bytes_read" (total() must not double-
-            # count the rerank bytes folded into it)
-            f_span.set(bytes_read_rerank=rerank_bytes)
+        # rerank-specific attr name: the ooc.query root owns the
+        # subtree's single "bytes_read" (total() must not double-
+        # count the rerank bytes folded into it)
+        f_span.set(bytes_read_rerank=rerank_bytes)
     result = SearchResult(
         dists=jnp.sqrt(top_d),
         ids=top_i,
@@ -448,6 +450,10 @@ def _host_refine(
         if slack_n["delta"] else 0.0,
         "eps_slack": slack_sum["epsilon"] / slack_n["epsilon"]
         if slack_n["epsilon"] else 0.0,
+        "loop_s": loop_s,
+        "gather_s": gather_t.seconds,
+        "sync_s": sync_t.seconds,
+        "host_syncs": sync_t.count,
     }
     return result, telem, rerank_bytes
 

@@ -39,7 +39,7 @@ def traced():
 
 # ------------------------------------------------------------- tracer
 def test_disabled_span_is_shared_noop():
-    assert not obs.enabled()
+    assert not obs.enabled() and not obs.profiling()
     sp = obs.span("x", a=1)
     assert sp is obs.NULL_SPAN
     with sp as s:
@@ -207,12 +207,16 @@ def test_oocstats_mapping_surface():
 def test_oocstats_aggregate_rates_and_weighted_slack():
     s1 = OocStats(hits=3, misses=1, hits_distinct=2, bytes_read=100,
                   stop_epsilon=2, eps_slack=1.0, stop_delta=1,
-                  delta_slack=4.0, pruning_ratio=0.5, iterations=2)
+                  delta_slack=4.0, pruning_ratio=0.5, iterations=2,
+                  loop_s=0.5, gather_s=0.25, sync_s=0.125, host_syncs=11)
     s2 = OocStats(hits=1, misses=3, hits_distinct=1, bytes_read=50,
                   stop_epsilon=0, eps_slack=99.0,  # zero-weight: ignored
-                  pruning_ratio=0.7, iterations=3)
+                  pruning_ratio=0.7, iterations=3,
+                  loop_s=1.0, gather_s=0.5, sync_s=0.25, host_syncs=17)
     agg = OocStats.aggregate([s1, s2])
     assert agg.bytes_read == 150 and agg.iterations == 5
+    assert (agg.loop_s, agg.gather_s, agg.sync_s, agg.host_syncs) == (
+        1.5, 0.75, 0.375, 28)
     assert agg.hits == 4 and agg.misses == 4
     np.testing.assert_allclose(agg.hit_rate, 4 / 8)
     np.testing.assert_allclose(agg.hit_rate_distinct, 3 / 7)
@@ -252,23 +256,162 @@ def test_span_attrs_match_stats_on_real_query(walk_data, walk_queries,
 
 def test_tracing_does_not_change_answers(walk_data, walk_queries,
                                          tmp_path):
+    """Bit-identical answers with obs on or off and the profiler
+    collecting or not: spans never sync or reorder device work."""
+    import jax
+
     ix = dstree.build(walk_data, leaf_cap=32)
     store = FrozenIndex.load(ix.save(str(tmp_path / "idx")),
                              resident="summaries")
-    plain = S.search_ooc(store, walk_queries, 5, G.epsilon(1.0),
-                         cache_leaves=6)
-    obs.enable()
-    try:
-        traced = S.search_ooc(store, walk_queries, 5, G.epsilon(1.0),
-                              cache_leaves=6)
-    finally:
-        obs.disable()
-        obs.clear()
-    np.testing.assert_array_equal(np.asarray(plain.result.ids),
-                                  np.asarray(traced.result.ids))
-    np.testing.assert_array_equal(np.asarray(plain.result.dists),
-                                  np.asarray(traced.result.dists))
-    assert plain.stats.leaves_visited == traced.stats.leaves_visited
+
+    def query():
+        return S.search_ooc(store, walk_queries, 5, G.epsilon(1.0),
+                            cache_leaves=6)
+
+    plain = query()
+    outs = []
+    for record, profile in ((True, False), (False, True), (True, True)):
+        if record:
+            obs.enable()
+        try:
+            if profile:
+                with jax.profiler.trace(str(tmp_path / f"tr{record}")):
+                    outs.append(query())
+            else:
+                outs.append(query())
+        finally:
+            obs.disable()
+            obs.clear()
+    for traced in outs:
+        np.testing.assert_array_equal(np.asarray(plain.result.ids),
+                                      np.asarray(traced.result.ids))
+        np.testing.assert_array_equal(np.asarray(plain.result.dists),
+                                      np.asarray(traced.result.dists))
+        assert plain.stats.leaves_visited == traced.stats.leaves_visited
+        assert plain.stats.host_syncs == traced.stats.host_syncs
+
+
+# ------------------------------------ the mirror into the profiler trace
+@pytest.fixture(scope="module")
+def resident_engine(walk_data):
+    import jax
+
+    from repro.core import IndexSpec
+    from repro.core.engine import DistributedEngine
+
+    eng = DistributedEngine(jax.make_mesh((1,), ("data",)),
+                            method="dstree")
+    return eng.build(walk_data, index=IndexSpec("dstree", leaf_cap=32))
+
+
+def _host_events(log_dir):
+    """(name, start_ns, end_ns) of every host event of the one
+    ``.xplane.pb`` under ``log_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    prof = ProfileData.from_file(path)
+    return [(ev.name, ev.start_ns, ev.end_ns)
+            for plane in prof.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_spans_reach_the_profiler_trace_with_obs_off(
+        walk_data, walk_queries, resident_engine, tmp_path):
+    """With obs off, a spilled search and a resident engine query write
+    their spans into the profiler's trace, inside an enclosing
+    ``bench.*``-style annotation on the same host clock, and the obs
+    tracer records nothing."""
+    import jax
+
+    ix = dstree.build(walk_data, leaf_cap=32)
+    store = FrozenIndex.load(ix.save(str(tmp_path / "idx")),
+                             resident="summaries")
+    resident_engine.query(walk_queries, 5, G.epsilon(1.0))  # warm
+    assert not obs.enabled()
+    obs.clear()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        with jax.profiler.TraceAnnotation("test.window"):
+            S.search_ooc(store, walk_queries, 5, G.epsilon(1.0),
+                         cache_leaves=6, prefetch=False)
+            res = resident_engine.query(walk_queries, 5, G.epsilon(1.0))
+            np.asarray(res.ids)
+    assert obs.tracer().spans() == []
+    assert not obs.profiling()
+    events = _host_events(tmp_path / "trace")
+    (w0, w1), = [(a, b) for n, a, b in events if n == "test.window"]
+    names = {n for n, _, _ in events}
+    assert {"ooc.query", "ooc.iteration", "ooc.tick", "ooc.gather",
+            "ooc.prefetch", "ooc.score", "ooc.stop", "ooc.sync",
+            "store.read", "store.h2d", "engine.query",
+            "engine.dispatch"} <= names
+    mine = [(a, b) for n, a, b in events
+            if n.split(".")[0] in ("ooc", "store", "engine")]
+    assert all(w0 <= a <= b <= w1 for a, b in mine)
+
+
+def test_span_states(tmp_path):
+    """Off: the shared no-op. Profiler only: an annotation that takes
+    the span surface and records no Span. Obs on: a recording Span."""
+    import jax
+
+    assert obs.span("x") is obs.NULL_SPAN
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs.profiling()
+        sp = obs.span("x", a=1)
+        assert sp is not obs.NULL_SPAN and not isinstance(sp, obs.Span)
+        with sp as s:
+            s.set(a=2)
+            s.add("a", 1)
+        obs.enable()
+        try:
+            assert isinstance(obs.span("x"), obs.Span)
+        finally:
+            obs.disable()
+    assert obs.tracer().spans() == []
+    assert obs.span("x") is obs.NULL_SPAN
+
+
+def test_resident_dispatch_is_timed(walk_queries, resident_engine):
+    res = resident_engine.query(walk_queries, 5, G.epsilon(1.0))
+    assert res.stats is None
+    assert res.dispatch_s > 0.0
+
+
+def test_loop_counters_are_their_spans(walk_data, walk_queries,
+                                       tmp_path, traced):
+    """The loop's always-on counters: a pinned count of device->host
+    reads per iteration, gathers and syncs inside the loop's time, and
+    (with obs recording) the counters equal their spans' stamps."""
+    ix = dstree.build(walk_data, leaf_cap=32)
+    store = FrozenIndex.load(ix.save(str(tmp_path / "idx")),
+                             resident="summaries")
+    nprobe = 4
+    st_ = S.search_ooc(store, walk_queries, 5, G.ng(nprobe),
+                       cache_leaves=6, prefetch_depth=1).stats
+    # every lane runs the whole rank budget: per iteration the reads of
+    # pos, the window's leaves, the next window (none in the last
+    # iteration), valid, next_lb and bsf
+    assert st_.stop_exhausted == walk_queries.shape[0]
+    assert st_.iterations == nprobe
+    assert st_.host_syncs == 6 * nprobe - 1
+    assert 0.0 < st_.gather_s + st_.sync_s <= st_.loop_s
+    syncs = traced.find("ooc.sync")
+    assert len(syncs) == st_.host_syncs
+    assert sum(sp.duration_s for sp in syncs) == st_.sync_s
+    assert sum(sp.duration_s
+               for sp in traced.find("ooc.gather")) == st_.gather_s
+    per_iter = {"ooc.tick", "ooc.gather", "ooc.prefetch", "ooc.score",
+                "ooc.stop"}
+    for name in per_iter:
+        assert len(traced.find(name)) == nprobe, name
+    its = traced.find("ooc.iteration")
+    assert {sp.parent for sp in traced.spans()
+            if sp.name in per_iter} == {sp.id for sp in its}
 
 
 # ------------------------------------------------- serve-side plumbing
