@@ -242,11 +242,20 @@ class ScoreCtx(NamedTuple):
 class Gathered(NamedTuple):
     """One iteration's gatherable candidates. ``pool[gather_idx]``
     yields the ENCODED candidate rows; ``row_idx`` maps the same slots
-    to padded row positions (ids / norms / re-rank reads)."""
-    pool: jax.Array        # [P, cols] gather pool (HBM data or cache slots)
+    to padded row positions (ids / norms / re-rank reads). ``buf`` is
+    the pool as its residency holds it, and what the scoring step is
+    handed: the [P, cols] HBM data array, or the cache's [S, M, cols]
+    slot pool, which :func:`refine_step` reads as [S*M, cols]."""
+    buf: jax.Array         # [P, cols] or [S, M, cols] gather pool
     gather_idx: jax.Array  # [B, V*M] int32 into pool
     row_idx: jax.Array     # [B, V*M] int32 padded row positions
     valid: jax.Array       # [B, V*M] bool
+
+    @property
+    def pool(self) -> jax.Array:
+        """``buf`` as the [P, cols] pool ``gather_idx`` indexes (a copy
+        of a 3-D slot pool when called eagerly)."""
+        return self.buf.reshape(-1, self.buf.shape[-1])
 
 
 def refine_step(ctx: ScoreCtx, pool: jax.Array, gather_idx: jax.Array,
@@ -281,6 +290,11 @@ def refine_step(ctx: ScoreCtx, pool: jax.Array, gather_idx: jax.Array,
     on every branch of the dispatch, identically in both residencies,
     so a deleted frozen row can never enter any running top-k."""
     k = top_d.shape[1]
+    if pool.ndim == 3:
+        # the cache's [S, M, cols] slot pool, passed as held: under the
+        # jit, merging its two major dims is a bitcast (M a multiple of
+        # the sublane tile), where an eager reshape copies the pool
+        pool = pool.reshape(-1, pool.shape[-1])
     if ctx.dead is not None:
         valid = valid & ~ctx.dead[row_idx]
     if pq:
@@ -382,11 +396,11 @@ class ResidentSource:
         idx, valid = candidate_layout(
             self.index.offsets, leaf, ok, self.index.max_leaf,
             self.index.data.shape[0] - 1)
-        return Gathered(pool=self.index.data, gather_idx=idx,
+        return Gathered(buf=self.index.data, gather_idx=idx,
                         row_idx=idx, valid=valid)
 
     def score(self, ctx, g, valid, top_d, top_i, *, share):
-        return refine_step(ctx, g.pool, g.gather_idx, g.row_idx, valid,
+        return refine_step(ctx, g.buf, g.gather_idx, g.row_idx, valid,
                            top_d, top_i, share=share, pq=False,
                            force_pallas=self.force_pallas)
 

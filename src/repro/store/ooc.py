@@ -50,6 +50,7 @@ ADC matrix never reaches HBM — docs/PERF.md §4).
 
 from __future__ import annotations
 
+import functools
 import warnings
 from typing import NamedTuple, Optional
 
@@ -98,6 +99,22 @@ _refine_step = jax.jit(refine.refine_step,
 _coop_mask = jax.jit(refine.coop_mask)
 
 
+@functools.partial(jax.jit, static_argnames=("max_leaf", "clamp"))
+def _slot_layout(offsets, leaf, slot, *, max_leaf, clamp):
+    """The spilled gather's device side as one compiled call: the shared
+    candidate_layout of the [B, V] window, and each position's index
+    into the cache's [S, M, C] slot pool read as [S*M, C]. ``slot`` is
+    the window's [B, V] cache slots, -1 where the visit is unusable
+    (out of rank or lane stopped): those positions are invalid and
+    gather slot 0, which the scoring step masks."""
+    b, v = leaf.shape
+    row_idx, valid = refine.candidate_layout(offsets, leaf, slot >= 0,
+                                             max_leaf, clamp)
+    gather_idx = (jnp.maximum(slot, 0)[:, :, None] * max_leaf
+                  + jnp.arange(max_leaf, dtype=jnp.int32)[None, None, :])
+    return gather_idx.reshape(b, v * max_leaf), row_idx, valid
+
+
 class CachedStoreSource:
     """LeafSource over a LeafStore: leaves reach the device through a
     DeviceLeafCache (disk -> host buffer -> one batched h2d scatter),
@@ -126,27 +143,24 @@ class CachedStoreSource:
     def track_width(self, k: int) -> int:
         return k
 
-    def gather(self, leaf: np.ndarray, ok: np.ndarray) -> Gathered:
+    def gather(self, leaf: np.ndarray, ok: np.ndarray,
+               leaf_dev: Optional[jax.Array] = None) -> Gathered:
         """Make the [B, V] window cache-resident and expose it as a
         refine_step gather pool. The full per-lane request list (dups
         included) feeds the cache so its per-request hit accounting
-        credits lanes sharing a leaf."""
-        m = self.store.max_leaf
-        b, v = leaf.shape
-        needed = leaf[ok]
-        slots = self.cache.get_slots(needed.tolist())
-        slot_of = dict(zip(needed.tolist(), slots.tolist()))
-        slot_arr = np.zeros_like(leaf)
-        for lf, s in slot_of.items():
-            slot_arr[leaf == lf] = s
-        gi = (slot_arr[:, :, None] * m
-              + np.arange(m)[None, None, :]).reshape(b, v * m)
-        row_idx, valid = refine.candidate_layout(
-            self.resident.offsets, jnp.asarray(leaf, jnp.int32),
-            jnp.asarray(ok), m, self.store.mmap.shape[0] - 1)
-        pool = self.cache.slots.reshape(-1, self.store.payload_cols)
-        return Gathered(pool=pool,
-                        gather_idx=jnp.asarray(gi, jnp.int32),
+        credits lanes sharing a leaf. ``leaf_dev`` is the same window
+        already on the device (the host loop's tick output); the layout
+        is one compiled call and the pool is the cache's own slot
+        buffer, handed over uncopied."""
+        slot = np.full(leaf.shape, -1, np.int32)
+        slot[ok] = self.cache.get_slots(leaf[ok].tolist())
+        if leaf_dev is None:
+            leaf_dev = jnp.asarray(leaf, jnp.int32)
+        gather_idx, row_idx, valid = _slot_layout(
+            self.resident.offsets, leaf_dev, jnp.asarray(slot),
+            max_leaf=self.store.max_leaf,
+            clamp=self.store.mmap.shape[0] - 1)
+        return Gathered(buf=self.cache.slots, gather_idx=gather_idx,
                         row_idx=row_idx, valid=valid)
 
     def prefetch(self, windows) -> None:
@@ -165,7 +179,7 @@ class CachedStoreSource:
                 pf.schedule(nxt)
 
     def score(self, ctx, g, valid, top_d, top_i, *, share):
-        return _refine_step(ctx, g.pool, g.gather_idx, g.row_idx,
+        return _refine_step(ctx, g.buf, g.gather_idx, g.row_idx,
                             valid, top_d, top_i, share=share,
                             pq=self.pq)
 
@@ -348,7 +362,7 @@ def _host_refine(
                 # delta here would be racy — the root span carries the
                 # authoritative total instead
                 pre_read = src.cache.bytes_read_sync if traced else 0
-                g = src.gather(leaf, ok)
+                g = src.gather(leaf, ok, leaf_dev=leaf_j)
                 if traced:
                     g_span.set(bytes_read_sync=(
                         src.cache.bytes_read_sync - pre_read))

@@ -461,6 +461,70 @@ def test_fill_reuses_device_pool_buffer(built, tmp_path):
     assert cache.slots.unsafe_buffer_pointer() == ptr
 
 
+@pytest.mark.parametrize("codec", ["f32", "bf16", "pq"])
+def test_spilled_gather_compiles_layout_and_scores_slot_pool(
+        built, queries_mod, tmp_path, monkeypatch, codec):
+    """The spilled gather lays out its window in one compiled call
+    (candidate_layout sees tracers, never concrete arrays) and scoring
+    reads the cache's own [S, M, C] slot buffer, not a flattened copy
+    of it made every iteration."""
+    from repro.core import refine
+    from repro.store import ooc
+    store = FrozenIndex.load(built.save(str(tmp_path / codec), codec=codec),
+                             resident="summaries")
+    cache = DeviceLeafCache(store, capacity_leaves=6)
+    traced, pools = [], []
+    real_layout, real_step = refine.candidate_layout, ooc._refine_step
+
+    def layout(offsets, leaf, ok, *a):
+        traced.append(isinstance(leaf, jax.core.Tracer)
+                      and isinstance(ok, jax.core.Tracer))
+        return real_layout(offsets, leaf, ok, *a)
+
+    def step(ctx, pool, *a, **kw):
+        pools.append((pool is cache.slots, pool.shape,
+                      pool.unsafe_buffer_pointer()
+                      == cache.slots.unsafe_buffer_pointer()))
+        return real_step(ctx, pool, *a, **kw)
+
+    monkeypatch.setattr(refine, "candidate_layout", layout)
+    monkeypatch.setattr(ooc, "_refine_step", step)
+    ooc._slot_layout.clear_cache()   # trace anew, through the wrapper
+    try:
+        out = S.search_ooc(store, queries_mod, 5, G.epsilon(1.0),
+                           cache=cache)
+    finally:
+        ooc._slot_layout.clear_cache()
+    assert traced and all(traced)
+    assert len(pools) == out.stats["iterations"]
+    want = (store.max_leaf, store.payload_cols)
+    for same, shape, same_ptr in pools:
+        assert same and same_ptr
+        assert shape == (cache.capacity,) + want
+
+
+@pytest.mark.parametrize("codec", ["f32", "bf16", "pq"])
+def test_spilled_search_bit_exact_across_batch_shapes(
+        built, queries_mod, tmp_path, codec):
+    """A spilled search gives the same ids and distances, bit for bit,
+    before and after a gather at a second batch shape (its own layout
+    and scoring programs) on the same warm cache; the lossless codecs
+    also match the in-memory search at both shapes."""
+    d = built.save(str(tmp_path / codec), codec=codec)
+    store = FrozenIndex.load(d, resident="summaries")
+    cache = DeviceLeafCache(store, capacity_leaves=6)
+    g = G.epsilon(1.0)
+    small = queries_mod[:3]
+    first = S.search_ooc(store, queries_mod, 5, g, cache=cache).result
+    second = S.search_ooc(store, small, 5, g, cache=cache).result
+    again = S.search_ooc(store, queries_mod, 5, g, cache=cache).result
+    assert_same(first, again)
+    if codec != "pq":
+        full = FrozenIndex.load(d)
+        assert_same(S.search(full, queries_mod, 5, g), again)
+        assert_same(S.search(full, small, 5, g), second)
+
+
 def test_prefetcher_reset_counters_quiesces(built, tmp_path):
     """Regression: a cold-pass read still in flight must not land its
     bytes AFTER reset_counters zeroes them (the straggler race that

@@ -94,3 +94,38 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, shapes = CASES[name]
     hlo = _compile(one_chip, fn, *shapes)
     assert "tpu_custom_call" in hlo, f"{name}: no Mosaic kernel emitted"
+
+
+@pytest.mark.parametrize("share", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_spilled_step_reads_slot_pool_uncopied(dtype, share, one_chip,
+                                               no_persistent_cache):
+    """The spilled scoring step is handed the leaf cache's [S, M, C]
+    slot pool as the cache holds it. At the real pool (1,024 slots of
+    512 rows of 256) the compiled step reads it as [S*M, C] through a
+    bitcast of the v5e's tiled layout, and copies no pool."""
+    import re
+
+    from repro.core.refine import ScoreCtx
+    from repro.store.ooc import _refine_step
+
+    slots, m, rows = 1024, 512, 4194304
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    ctx = ScoreCtx(qf=sds((32, N_LEN), jnp.float32),
+                   ids=sds((rows,), jnp.int32),
+                   norms=sds((rows,), jnp.float32), luts=None)
+    idx = sds((32, m), jnp.int32)
+    hlo = _refine_step.lower(
+        ctx, sds((slots, m, N_LEN), dtype), idx, idx,
+        sds((32, m), jnp.bool_), sds((32, 10), jnp.float32),
+        sds((32, 10), jnp.int32), share=share, pq=False,
+    ).compile().as_text()
+    pool = re.search(r"(%pool[.\d]*) = \S+ parameter\(", hlo).group(1)
+    uses = [ln for ln in hlo.splitlines()
+            if re.search(re.escape(pool) + r"[,)]", ln)
+            and "parameter(" not in ln]
+    assert uses and all(" bitcast(" in ln for ln in uses), uses
+    assert f"[{slots * m},{N_LEN}]" in uses[0]
