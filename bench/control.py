@@ -32,12 +32,13 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
+    cell = spec.load(ROOT, args.workload)
     try:
-        harness.require_accelerator(1)
+        harness.require_accelerator(cell.chips)
     except harness.NoAccelerator as e:
         print(f"control: {e}", file=sys.stderr)
         return 3
-    harness.enable_compile_cache(spec.load(ROOT, args.workload).config)
+    harness.enable_compile_cache(cell.config)
 
     def factory(cell, rows, data_dev, spill_dir):
         return reference.ReferenceEngine(data_dev, "high")

@@ -1,13 +1,15 @@
 """One run of one cell: set-up, the measured window, the check, the line.
 
 Set-up (counted in ``setup_s``, from process start to the first request
-due): the collection made on the device from the seed in one call and
+due): the collection made on the device from the seed in one call
+(sharded by rows over the cell's chips where it has more than one) and
 brought to the host for the build, the query pool, the engine build (and
 spill), and a warm-up of every padded batch shape the lane can form.
 The window then drives ``repro.serve.loop.ServeFront.submit`` with the
-cell's traffic for ``--seconds``. After it closes: the peak device
-memory is read, the engine is freed, the collection is made again on the
-device and the reference grades every answer of the window.
+cell's traffic for ``--seconds``. After it closes: the peak memory of
+the fullest chip is read, the engine is freed, the collection is made
+again on the device and the reference grades every answer of the
+window.
 
 With ``--trace 1`` the same run records a profiler trace of the window
 and prints the cell's per-layer metrics instead of its end-to-end ones.
@@ -20,6 +22,7 @@ import gc
 import json
 import math
 import os
+import resource
 import shutil
 import statistics
 import sys
@@ -136,8 +139,9 @@ class RunView:
     compile_s: float
     batches: int                 # lane batches drained in the window
     batched: int                 # requests in them
-    trace: Optional[devtrace.Reduction]
-    peaks: dict
+    trace: Optional[devtrace.Reduction]   # averaged over the chips
+    peaks: dict                  # one chip's
+    chips: int
 
     @property
     def answered(self) -> List[load.Record]:
@@ -249,14 +253,33 @@ class System:
         shutil.rmtree(self.spill_dir, ignore_errors=True)
 
 
-def collection(cfg: dict, words):
+def collection(cfg: dict, words, chips: int):
     """The configuration's collection in the order of the run's seed
-    (:func:`bench.data.collection`), on the device."""
+    (:func:`bench.data.collection`), on the device; over more than one
+    chip, sharded by rows over the first ``chips`` devices
+    (:func:`bench.data.sharded_collection`)."""
+    import jax
     import jax.numpy as jnp
 
     base = jnp.asarray(bdata.seed_words(int(cfg["collection_seed"])))
+    if chips > 1:
+        return bdata.sharded_collection(base, words, int(cfg["rows"]),
+                                        int(cfg["series_len"]),
+                                        jax.devices()[:chips])
     return bdata.collection(base, words, int(cfg["rows"]),
                             int(cfg["series_len"]))
+
+
+def chip_peaks(devices) -> List[Optional[int]]:
+    """Each device's ``peak_bytes_in_use`` (None where it reports
+    none)."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
+
+
+def memory_peak(peaks: List[Optional[int]]) -> Optional[int]:
+    """The fullest chip's peak: the largest of :func:`chip_peaks`."""
+    return max((p for p in peaks if p is not None), default=None)
 
 
 def prepare(cell: spec.Cell, seed: int, seconds: float,
@@ -277,7 +300,7 @@ def prepare(cell: spec.Cell, seed: int, seconds: float,
     split: Dict[str, float] = {}
     t = clock()
     words = jnp.asarray(bdata.seed_words(seed))
-    dev, scale, where = collection(cfg, words)
+    dev, scale, where = collection(cfg, words, cell.chips)
     rows = np.asarray(dev)
     scale, where = float(scale), np.asarray(where)
     split["data"] = clock() - t
@@ -364,7 +387,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         h1 = hist.snapshot()
         front.stop(drain=True)
         front = None
-        peak = (devs[0].memory_stats() or {}).get("peak_bytes_in_use")
+        by_chip = chip_peaks(devs[:cell.chips])
+        peak = memory_peak(by_chip)
         red = devtrace.reduce_dir(log_dir) if trace else None
         calls = probe.calls
         del probe
@@ -386,7 +410,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
 
         # the reference, once the window is closed and the engine freed
         t = clock()
-        dev, _, _ = collection(cfg, system.words)
+        dev, _, _ = collection(cfg, system.words, cell.chips)
         asked = sorted({r.query for r in win.records if r.answered})
         top = reference.reference_topk(dev, system.rows, system.pool[asked],
                                        k)
@@ -395,6 +419,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                              {q: i for i, q in enumerate(asked)}, top, k,
                              tr["guarantee"], cell.limits)
         _log(f"reference over {len(asked)} queries: {clock() - t:.3f} s")
+        _log(f"peak bytes by chip {by_chip}; host peak RSS "
+             f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss} KiB")
     finally:
         if front is not None:
             front.stop(drain=False)
@@ -409,7 +435,7 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                        compile_s=compile_s,
                        batches=h1["count"] - h0["count"],
                        batched=int(round(h1["sum"] - h0["sum"])),
-                       trace=red, peaks=kind_peaks)
+                       trace=red, peaks=kind_peaks, chips=cell.chips)
         metrics = {}
         for m in cell.per_layer:
             v = cell.reader(m.name)(view)

@@ -5,6 +5,9 @@ it scores every row of the collection (made again from the seed) against
 each query on the device, keeps ``CANDIDATES`` per query, and ranks
 those on the host in float64 from direct differences. Only the ranking
 in float64 is the reference; the device pass just narrows the field.
+Over a collection sharded by rows, each device scores its own rows and
+the parts' candidates are merged before the ranking: the kc smallest of
+the whole lie among the kc smallest of each part.
 
 The device pass is an exact top-k of its own scores, in blocks of rows:
 per group of ``GROUP`` rows the group minimum, the ``kc`` groups with
@@ -100,17 +103,43 @@ def nearest(data, queries, kc: int, precision: str = "highest"):
     return jax.lax.fori_loop(0, n_rows // rb, body, (best_d, best_i))
 
 
+def sharded(data) -> bool:
+    """Whether ``data`` is spread over more than one device."""
+    return len(data.sharding.device_set) > 1
+
+
+def sharded_nearest(data, queries: np.ndarray, kc: int, precision: str):
+    """:func:`nearest` over ``data`` sharded by rows: each device scores
+    its own rows, its ids offset by its first row, and the kc smallest
+    of the parts' candidates are kept (ties by id). Host arrays (d2
+    [Q, kc], ids [Q, kc])."""
+    out = [(s.index[0].start,
+            nearest(s.data, jax.device_put(queries, s.device), kc,
+                    precision))
+           for s in data.addressable_shards]
+    d2 = np.concatenate([np.asarray(d) for _, (d, _) in out], axis=1)
+    ids = np.concatenate([np.asarray(i) + lo for lo, (_, i) in out],
+                         axis=1)
+    order = np.lexsort((ids, d2), axis=1)[:, :kc]
+    return (np.take_along_axis(d2, order, 1),
+            np.take_along_axis(ids, order, 1))
+
+
 def candidates(data, queries: np.ndarray, kc: int) -> np.ndarray:
     """ids [Q, kc] of :func:`nearest` at ``highest``, over query blocks
     of QUERY_BLOCK (the last one padded), so that the scores of a block
-    fit beside the collection."""
+    fit beside the collection. Over a collection sharded by rows, each
+    device scores its own rows (:func:`sharded_nearest`)."""
     out = []
     for lo in range(0, queries.shape[0], QUERY_BLOCK):
         qb = queries[lo:lo + QUERY_BLOCK]
         pad = QUERY_BLOCK - qb.shape[0]
         if pad:
             qb = np.concatenate([qb, np.repeat(qb[-1:], pad, 0)])
-        _, ids = nearest(data, jnp.asarray(qb), kc, "highest")
+        if sharded(data):
+            _, ids = sharded_nearest(data, qb, kc, "highest")
+        else:
+            _, ids = nearest(data, jnp.asarray(qb), kc, "highest")
         out.append(np.asarray(ids)[:QUERY_BLOCK - pad])
     return np.concatenate(out)
 
@@ -150,7 +179,8 @@ class Answer(NamedTuple):
 
 class ReferenceEngine:
     """The reference put in the engine's place, at ``precision``: every
-    query brute-forced over the collection. With ``precision="high"`` it
+    query brute-forced over the collection, each device over its own
+    rows where the collection is sharded. With ``precision="high"`` it
     is the lower-precision control that ``correct`` has to reject."""
 
     def __init__(self, data_dev, precision: str):
@@ -158,8 +188,14 @@ class ReferenceEngine:
         self.precision = precision
 
     def query(self, queries, k: int, g=None, **_kw) -> Answer:
-        d2, ids = nearest(self.data, jnp.asarray(queries, jnp.float32), k,
-                          self.precision)
+        if sharded(self.data):
+            d2, ids = sharded_nearest(self.data,
+                                      np.asarray(queries, np.float32), k,
+                                      self.precision)
+            d2, ids = jnp.asarray(d2), jnp.asarray(ids)
+        else:
+            d2, ids = nearest(self.data, jnp.asarray(queries, jnp.float32),
+                              k, self.precision)
         b, n = queries.shape[0], self.data.shape[0]
         return Answer(dists=jnp.sqrt(d2), ids=ids,
                       leaves_visited=jnp.zeros(b, jnp.int32),

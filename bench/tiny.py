@@ -34,6 +34,22 @@ def make_root(tmp, rows: int = ROWS) -> str:
     return root
 
 
+def add_cell(root: str, name: str, like: str, chips: int) -> None:
+    """A cell ``name`` in the copy at ``root``: ``like``'s entry, traffic
+    and limits on ``chips`` chips."""
+    path = os.path.join(root, "BENCHMARK.json")
+    bench = read_json(path)
+    entry = dict(next(w for w in bench["workloads"] if w["name"] == like),
+                 name=name, chips=chips)
+    bench["workloads"].append(entry)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if like in m.get("workloads", []):
+            m["workloads"].append(name)
+    write_json(path, bench)
+    limits = os.path.join(root, "bench", "limits", "{}.json")
+    write_json(limits.format(name), read_json(limits.format(like)))
+
+
 def write_json(path: str, obj) -> None:
     with open(path, "w") as f:
         json.dump(obj, f, indent=1)
